@@ -202,25 +202,16 @@ def summarize_module(mod: HloModule) -> Dict:
 
 
 def _memory_dict(compiled) -> Dict:
-    try:
-        ma = compiled.memory_analysis()
-        return {k: getattr(ma, k) for k in (
-            "argument_size_in_bytes", "output_size_in_bytes",
-            "temp_size_in_bytes", "alias_size_in_bytes",
-            "generated_code_size_in_bytes") if hasattr(ma, k)}
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)}
+    ma = compiled.memory_analysis()
+    return {k: getattr(ma, k) for k in (
+        "argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "alias_size_in_bytes",
+        "generated_code_size_in_bytes") if hasattr(ma, k)}
 
 
 def _cost_dict(compiled) -> Dict:
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):
-            ca = ca[0]
-        return {k: float(v) for k, v in ca.items()
-                if isinstance(v, (int, float))}
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)}
+    return {k: float(v) for k, v in compiled.cost_analysis().items()
+            if isinstance(v, (int, float))}
 
 
 def capture_step(step_fn, abstract_args, in_shardings, mesh,
@@ -235,9 +226,8 @@ def capture_step(step_fn, abstract_args, in_shardings, mesh,
         kw["in_shardings"] = in_shardings
     if out_shardings is not None:
         kw["out_shardings"] = out_shardings
-    from repro.parallel.mesh import mesh_context
     jitted = jax.jit(step_fn, donate_argnums=donate_argnums, **kw)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*abstract_args)
         t_lower = time.time() - t0
         t0 = time.time()

@@ -20,7 +20,7 @@ from typing import Dict, List, Optional
 from repro.core import chakra
 from repro.core.hlo_parse import (COLLECTIVE_OPS, HloModule, Instruction,
                                   instruction_flops, parse_permute_pairs,
-                                  parse_replica_groups, while_trip_count)
+                                  parse_replica_groups, loop_trip_count)
 
 # ops that never become nodes: forward deps through them
 _ALIAS_OPS = {"tuple", "get-tuple-element", "parameter", "bitcast",
@@ -61,8 +61,7 @@ def _comp_cost(mod: HloModule, comp_name: str, mult: int = 1):
             continue
         if ins.opcode == "while":
             body = ins.attrs.get("body", "").lstrip("%")
-            cond = ins.attrs.get("condition", "").lstrip("%")
-            trips = while_trip_count(mod, cond)
+            trips = loop_trip_count(mod, ins)
             f, b = _comp_cost(mod, body, 1)
             flops += f * trips
             bytes_ += b * trips
@@ -195,8 +194,7 @@ class _Builder:
 
     def _emit_while(self, ins: Instruction, operand_vals, deps, prefix):
         body = ins.attrs.get("body", "").lstrip("%")
-        cond = ins.attrs.get("condition", "").lstrip("%")
-        trips = while_trip_count(self.mod, cond)
+        trips = loop_trip_count(self.mod, ins)
         if not _computation_has_collective(self.mod, body) or trips > _MAX_EXPAND:
             f, b = _comp_cost(self.mod, body, trips)
             nid = self.g.add(prefix + ins.name, chakra.COMP, deps=deps,

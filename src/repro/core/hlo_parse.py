@@ -32,6 +32,7 @@ COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                   "collective-permute", "collective-broadcast")
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
 
 
 _FLOAT_TYPES = {"f64", "f32", "bf16", "f16", "f8e4m3fn", "f8e5m2"}
@@ -66,9 +67,12 @@ class Shape:
 
 
 def parse_shape_str(s: str) -> List[Shape]:
-    """'(f32[2,3]{1,0}, bf16[4])' or 'f32[2,3]{1,0}' -> list of Shape."""
+    """'(f32[2,3]{1,0}, bf16[4])' or 'f32[2,3]{1,0}' -> list of Shape.
+
+    Layouts are dropped first: a TPU layout carries tiling and memory space
+    (``{1,0:T(8,128)(2,1)S(1)}``), which is never part of the dims."""
     out = []
-    for m in _SHAPE_RE.finditer(s):
+    for m in _SHAPE_RE.finditer(_LAYOUT_RE.sub("", s)):
         dtype, dims = m.group(1), m.group(2)
         if dtype not in DTYPE_BYTES:
             continue
@@ -136,10 +140,48 @@ class HloModule:
 
 
 # instruction line:  %name = TYPE opcode(...operands...), attr=..., ...
-# TYPE may be a tuple '(f32[..], ..)'; the opcode is the last word before the
-# first call-paren, so match the type lazily.
-_INSTR_RE = re.compile(
-    r"^\s*(ROOT\s+)?%?([\w.-]+)\s*=\s*(.*?)([\w-]+)\((.*)$")
+# TYPE is a tuple '(f32[..]{..}, ..)' or 'dtype[dims]{layout}'.  A TPU layout
+# holds parenthesised tiles ('{1,0:T(8,128)(2,1)S(1)}'), so the type is
+# delimited by bracket balance, never by the first 'word('.
+_INSTR_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*")
+_ARRAY_TYPE_RE = re.compile(r"\w+\[[\d,]*\]")
+_OPCODE_RE = re.compile(r"\s*([\w-]+)\((.*)$")
+
+
+def _balanced_end(s: str, i: int) -> int:
+    """Index just past the bracket group opening at s[i]."""
+    depth = 0
+    for j in range(i, len(s)):
+        if s[j] in "({":
+            depth += 1
+        elif s[j] in ")}":
+            depth -= 1
+            if depth == 0:
+                return j + 1
+    return -1
+
+
+def _split_instruction(st: str):
+    """'%n = TYPE opcode(rest' -> (name, TYPE, opcode, rest) or None."""
+    hm = _INSTR_HEAD_RE.match(st)
+    if not hm:
+        return None
+    i = hm.end()
+    if st.startswith("(", i):
+        end = _balanced_end(st, i)
+    else:
+        am = _ARRAY_TYPE_RE.match(st, i)
+        if not am:
+            return None
+        end = am.end()
+        if st.startswith("{", end):
+            end = _balanced_end(st, end)
+    if end < 0:
+        return None
+    om = _OPCODE_RE.match(st, end)
+    if not om:
+        return None
+    return hm.group(1), st[i:end], om.group(1), om.group(2)
 
 
 def _parse_operands(argstr: str) -> List[str]:
@@ -202,9 +244,9 @@ def parse_hlo(text: str) -> HloModule:
                 cur_name = None
                 cur_instrs = []
             continue
-        im = _INSTR_RE.match(st)
-        if im and cur_name is not None:
-            _, name, typestr, opcode, rest = im.groups()
+        im = _split_instruction(st) if cur_name is not None else None
+        if im:
+            name, typestr, opcode, rest = im
             operands, tail = _parse_operands(rest)
             attrs: Dict[str, str] = {}
             for am in re.finditer(
@@ -278,6 +320,19 @@ def while_trip_count(mod: HloModule, cond_name: str) -> int:
     return best
 
 
+_KNOWN_TRIPS_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+
+
+def loop_trip_count(mod: HloModule, while_ins: Instruction) -> int:
+    """Trips of a ``while``: the count XLA proved and recorded in the loop's
+    ``backend_config`` (``"known_trip_count":{"n":"48"}``) where present,
+    else the condition heuristic of ``while_trip_count``."""
+    m = _KNOWN_TRIPS_RE.search(while_ins.raw)
+    if m:
+        return int(m.group(1))
+    return while_trip_count(mod, while_ins.attrs.get("condition", "").lstrip("%"))
+
+
 def walk_instructions(mod: HloModule, comp_name: Optional[str] = None,
                       multiplier: int = 1, _seen=None):
     """Yield (Instruction, multiplier, computation_name) over the entry
@@ -293,8 +348,7 @@ def walk_instructions(mod: HloModule, comp_name: Optional[str] = None,
         yield ins, multiplier, comp_name
         if ins.opcode == "while":
             body = ins.attrs.get("body", "").lstrip("%")
-            cond = ins.attrs.get("condition", "").lstrip("%")
-            trips = while_trip_count(mod, cond)
+            trips = loop_trip_count(mod, ins)
             yield from walk_instructions(mod, body, multiplier * trips)
         elif ins.opcode == "conditional":
             for key in ("true_computation", "false_computation"):
@@ -334,20 +388,71 @@ def dot_flops(mod: HloModule, ins: Instruction, comp_name: str) -> float:
     return 2.0 * out.elems * k
 
 
+def conv_flops(mod: HloModule, ins: Instruction, comp_name: str) -> float:
+    """2 * out elems * (input features * window taps) of a ``convolution``.
+
+    The TPU compiler emits matmuls as convolutions (``dim_labels=bf_io->bf``):
+    the contraction is the kernel's ``i`` dim times the window taps that
+    meet an input element.  Batched matmuls put their batch dims in the
+    window (``size=n lhs_dilate=n``, or an input dim of 1 padded to n), where
+    one tap of the n does, so window dim k counts
+    min(ceil(size_k / lhs_dilate_k), input extent_k) taps.
+    """
+    if not ins.shapes:
+        return 0.0
+    out = ins.shapes[0]
+    labels = ins.attrs.get("dim_labels", "")
+    lhs, rhs = (
+        [_operand_shape(mod, comp_name, o) for o in ins.operands[:2]]
+        if len(ins.operands) > 1 else (None, None))
+    k = 1
+    if lhs is not None and rhs is not None and "_" in labels:
+        lhs_labels, rhs_labels = labels.split("->")[0].split("_")
+        if len(rhs_labels) == len(rhs.dims) and \
+                len(lhs_labels) == len(lhs.dims):
+            k = rhs.dims[rhs_labels.index("i")] if "i" in rhs_labels else 1
+            n_sp = sum(c.isdigit() for c in rhs_labels)
+            dm = re.search(r"lhs_dilate=([\dx]+)", ins.raw)
+            dilate = ([int(x) for x in dm.group(1).split("x")] if dm
+                      else [1] * n_sp)
+            for s in range(n_sp):
+                size = rhs.dims[rhs_labels.index(str(s))]
+                extent = lhs.dims[lhs_labels.index(str(s))]
+                k *= min(-(-size // dilate[s]), extent)
+    return 2.0 * out.elems * k
+
+
+def _called_flops(mod: HloModule, comp_name: str) -> float:
+    """FLOPs of the dots and convolutions in a fused computation, through
+    nested fusions."""
+    sub = mod.computations.get(comp_name)
+    if sub is None:
+        return 0.0
+    total = 0.0
+    for i in sub.instructions:
+        if i.opcode == "dot":
+            total += dot_flops(mod, i, comp_name)
+        elif i.opcode == "convolution":
+            total += conv_flops(mod, i, comp_name)
+        elif i.opcode == "fusion":
+            total += _called_flops(mod, i.attrs.get("calls", "").lstrip("%"))
+    return total
+
+
 def instruction_flops(mod: HloModule, ins: Instruction, comp_name: str) -> float:
+    """FLOPs of one instruction, counting only matmul-class work.
+
+    A fusion counts the dots and convolutions it calls, whatever its name:
+    the TPU compiler fuses matmuls into fusions named after their epilogue
+    (``multiply_add_fusion``, ``bitcast_dynamic-update-slice_fusion``).
+    A Pallas kernel is a ``custom-call`` (``custom_call_target=
+    "tpu_custom_call"``) whose body is opaque Mosaic code: it contributes
+    0 FLOPs here, and its cost only enters through the caller's own
+    estimate."""
     if ins.opcode == "dot":
         return dot_flops(mod, ins, comp_name)
     if ins.opcode == "fusion":
-        # dots are never fused into loop fusions by XLA:CPU/TPU at the top
-        # level except as output fusions named *dot*; approximate via name
-        if "dot" in ins.name or "matmul" in ins.name or "conv" in ins.name:
-            called = ins.attrs.get("calls", "").lstrip("%")
-            sub = mod.computations.get(called)
-            if sub:
-                return sum(dot_flops(mod, i, called)
-                           for i in sub.instructions if i.opcode == "dot")
-        return 0.0
+        return _called_flops(mod, ins.attrs.get("calls", "").lstrip("%"))
     if ins.opcode == "convolution":
-        out = ins.shapes[0] if ins.shapes else None
-        return 2.0 * out.elems if out else 0.0
+        return conv_flops(mod, ins, comp_name)
     return 0.0

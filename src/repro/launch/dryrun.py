@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell on the
 production mesh with 512 placeholder host devices, print memory_analysis()
 and cost_analysis(), and persist the Flint capture summary for the roofline.
@@ -9,6 +7,16 @@ Usage:
   python -m repro.launch.dryrun --all            # every cell, both meshes
                                                  # (one subprocess per cell)
 """
+import os
+
+# 512 placeholder devices live on the host platform: pin it before any
+# backend starts (on a TPU machine JAX would otherwise take the chip) and
+# add the device count to whatever XLA_FLAGS the caller set.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=512")))
+
 import argparse
 import json
 import subprocess

@@ -5,11 +5,10 @@ jax device state.
 """
 from __future__ import annotations
 
-import jax
-from jax.sharding import AxisType
+from repro.parallel.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)

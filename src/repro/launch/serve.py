@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 
 def main(argv=None):
+    """Returns (sampled ids (B, steps), the last decode step's logits)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-4b")
     ap.add_argument("--smoke", action="store_true")
@@ -69,8 +70,10 @@ def main(argv=None):
     print(f"[serve] decode {args.steps - 1} steps: {t_dec * 1e3:.1f}ms "
           f"({args.batch * (args.steps - 1) / max(t_dec, 1e-9):.0f} tok/s)")
     print(f"[serve] sample output ids: {toks[0, :16].tolist()}")
-    return toks
+    return toks, logits
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -11,13 +11,20 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
+from typing import NamedTuple
 
 import jax
-import numpy as np
 
 
-def main(argv=None):
+class TrainSetup(NamedTuple):
+    cfg: object
+    model: object
+    parallel: object
+    data: object
+    step: object            # un-jitted (TrainState, batch) -> (state, metrics)
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--smoke", action="store_true",
@@ -34,18 +41,15 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--inject-fault-at", type=int, default=-1)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build(args) -> TrainSetup:
+    """The model, configs and train step that main() runs for `args`."""
     from repro.configs.base import ParallelConfig
     from repro.configs.registry import get_config
     from repro.models import build_model
-    from repro.train import (DataConfig, DataIterator, OptConfig, TrainState,
-                             init_train_state, latest_step, make_train_step,
-                             restore_checkpoint, save_checkpoint)
-    from repro.train.fault import (FaultInjector, PreemptionHandler,
-                                   SimulatedFault, StepTimer,
-                                   StragglerMonitor, run_with_retry)
-    from repro.train.optimizer import abstract_opt_state
+    from repro.train import DataConfig, OptConfig, make_train_step
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -57,12 +61,26 @@ def main(argv=None):
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                     global_batch=args.batch, memory_len=model.memory_len(),
                     d_model=cfg.d_model)
+    return TrainSetup(cfg, model, par, dc, make_train_step(model, opt, par))
 
-    step_fn = jax.jit(make_train_step(model, opt, par))
-    state = init_train_state(model, jax.random.PRNGKey(0), par)
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    from repro.train import (DataIterator, init_train_state, latest_step,
+                             restore_checkpoint, save_checkpoint)
+    from repro.train.fault import (FaultInjector, PreemptionHandler,
+                                   StepTimer, StragglerMonitor, run_with_retry)
+
+    setup = build(args)
+    # the old state is dead once the step returns: donating it lets the new
+    # state reuse its buffers (params + f32 Adam moments, ~12 B/param)
+    step_fn = jax.jit(setup.step, donate_argnums=(0,))
+    state = init_train_state(setup.model, jax.random.PRNGKey(0),
+                             setup.parallel)
     start_step = 0
 
-    ckpt_dir = args.ckpt_dir or os.path.join("checkpoints", cfg.name)
+    ckpt_dir = args.ckpt_dir or os.path.join("checkpoints", setup.cfg.name)
     if args.resume:
         last = latest_step(ckpt_dir)
         if last is not None:
@@ -71,7 +89,7 @@ def main(argv=None):
             start_step = meta["step"]
             print(f"[train] resumed from step {start_step}")
 
-    it = DataIterator(dc, start_step=start_step)
+    it = DataIterator(setup.data, start_step=start_step)
     preempt = PreemptionHandler().install()
     monitor = StragglerMonitor()
     injector = FaultInjector(
@@ -109,6 +127,7 @@ def main(argv=None):
                 print(f"[train] preempted; checkpointed at {step + 1}")
                 break
 
+    os.makedirs(ckpt_dir, exist_ok=True)
     with open(os.path.join(ckpt_dir, "metrics.json"), "w") as f:
         json.dump(metrics_log, f, indent=1)
     print(f"[train] done; final loss "
@@ -117,4 +136,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

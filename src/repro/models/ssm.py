@@ -70,8 +70,11 @@ def ssd_chunked(x, dt, A, B, C, chunk):
         cum = jnp.cumsum(a, axis=2)                       # inclusive
         # intra-chunk: scores[i,j] = (C_i . B_j) * exp(cum_i - cum_j) * dt_j
         seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (b,nc,Q,Q,h)
-        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-        L = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0)
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))[None, None, :, :, None]
+        # mask before the exp: above the diagonal seg > 0 overflows, and the
+        # where's zero cotangent times exp(seg) = inf would make the
+        # gradient NaN
+        L = jnp.exp(jnp.where(causal, seg, -jnp.inf))
         cb = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)        # (b,nc,Q,Q)
         scores = cb[..., None] * L * dtc[:, :, None, :, :]    # (b,nc,Q,Q,h)
         y_intra = jnp.einsum("bcijh,bcjhp->bcihp", scores, xc)

@@ -9,39 +9,19 @@ pipeline axis when ParallelConfig.pipeline_stages > 1.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older jax has neither the
-    # enum nor the ``axis_types=`` kwarg on jax.make_mesh.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 POD_AXIS = "pod"
 
 
-def make_mesh(shape, axes):
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (newer jax explicit-sharding
-    API); a no-op context on older jax, where NamedSharding-driven
-    jit/lowering needs no ambient mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext()
+def make_mesh(shape, axes, devices=None):
+    """Mesh with Auto (GSPMD-propagated) axes; ``devices`` defaults to all."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def axis_size(mesh, name: str) -> int:

@@ -1,11 +1,7 @@
 """End-to-end Flint capture: cluster-free lower/compile -> Chakra graph ->
 passes -> simulator (the paper's pipeline on an 8-fake-device mesh)."""
-import pytest
 
 
-@pytest.mark.skip(reason="pre-existing at seed: jax 0.4.37 capture-fidelity "
-                         "gap (per-layer all-gather deps not recovered from "
-                         "scanned HLO) — see ROADMAP 'jax 0.4.37 compat'")
 def test_capture_pipeline_end_to_end(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp

@@ -1,17 +1,11 @@
 """int8-compressed DP gradient all-reduce (error feedback) on fake devices."""
-import pytest
-
-pytestmark = pytest.mark.skip(
-    reason="pre-existing at seed: parallel/collectives.py's shard_map-based "
-           "compressed all-reduce fails on jax 0.4.37 — see ROADMAP "
-           "'jax 0.4.37 compat'")
 
 
 def test_compressed_allreduce_matches_mean(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.parallel.mesh import make_mesh, mesh_context
+from repro.parallel.mesh import make_mesh
 from repro.parallel.collectives import (make_compressed_value_and_grad,
                                         init_error_state)
 mesh = make_mesh((4, 2), ("data", "model"))
@@ -27,7 +21,7 @@ x = jax.device_put(np.random.RandomState(1).randn(B, D).astype(np.float32),
 batch = {"x": x}
 run = make_compressed_value_and_grad(loss_fn, mesh, ("data",))
 err = init_error_state(w, 4)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     loss, met, g, err = jax.jit(run)(w, batch, err)
 (ref_loss, _), ref_g = jax.value_and_grad(loss_fn, has_aux=True)(w, batch)
 assert abs(float(loss) - float(ref_loss)) < 1e-5
@@ -42,7 +36,7 @@ def test_error_feedback_reduces_bias_over_steps(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.parallel.mesh import make_mesh, mesh_context
+from repro.parallel.mesh import make_mesh
 from repro.parallel.collectives import (make_compressed_value_and_grad,
                                         init_error_state)
 mesh = make_mesh((8,), ("data",))
@@ -57,7 +51,7 @@ run = jax.jit(make_compressed_value_and_grad(loss_fn, mesh, ("data",)))
 err = init_error_state(w, 8)
 accum_c = jnp.zeros((D,))
 accum_r = jnp.zeros((D,))
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     for i in range(20):
         loss, met, g, err = run(w, {"x": x}, err)
         (_, _), gr = jax.value_and_grad(loss_fn, has_aux=True)(w, {"x": x})
@@ -75,7 +69,7 @@ def test_train_step_with_compression_learns(subproc):
     out = subproc("""
 import jax, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.parallel.mesh import make_mesh, mesh_context
+from repro.parallel.mesh import make_mesh
 from repro.configs.base import ParallelConfig
 from repro.configs.registry import get_config
 from repro.models import build_model
@@ -93,7 +87,7 @@ step = jax.jit(make_train_step(m, OptConfig(lr=1e-2, warmup_steps=5,
 it = DataIterator(DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
                              global_batch=8))
 losses = []
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     for i in range(30):
         state, metrics = step(state, next(it))
         losses.append(float(metrics["loss"]))

@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from repro.core.hlo_parse import (parse_hlo, parse_replica_groups,
+from repro.core.hlo_parse import (Shape, conv_flops, loop_trip_count,
+                                  parse_hlo, parse_replica_groups,
                                   parse_shape_str, while_trip_count,
                                   walk_instructions, instruction_flops)
 
@@ -124,3 +125,79 @@ assert fl == expect, (fl, expect)
 print("flops ok", fl)
 """)
     assert "flops ok" in out
+
+
+# TPU-compiled text: tiled layouts ({1,0:T(8,128)(2,1)S(1)}), matmuls as
+# convolutions inside fusions named after their epilogue, a batched matmul
+# with its batch dims in the convolution window, and a loop whose proven
+# trip count (4) differs from its condition's constant (7)
+TPU_SAMPLE = """
+HloModule jit_step, num_partitions=1
+
+%fused_computation.1 (param_0: bf16[256,512], param_1: bf16[512,2048]) -> bf16[256,2048] {
+  %param_0 = bf16[256,512]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1 = bf16[512,2048]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.9 = bf16[256,2048]{1,0:T(8,128)(2,1)S(1)} convolution(%param_0, %param_1), dim_labels=bf_io->bf, metadata={op_name="jit(step)/dot_general"}
+}
+
+%cond.2 (p: (s32[], bf16[256,512], bf16[512,2048])) -> pred[] {
+  %p = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)}, bf16[512,2048]{1,0:T(8,128)(2,1)}) parameter(0)
+  %gte = s32[]{:T(128)} get-tuple-element(%p), index=0
+  %c = s32[]{:T(128)} constant(7)
+  ROOT %lt = pred[]{:T(512)} compare(%gte, %c), direction=LT
+}
+
+%body.3 (q: (s32[], bf16[256,512], bf16[512,2048])) -> (s32[], bf16[256,512], bf16[512,2048]) {
+  %q = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)}, bf16[512,2048]{1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%q), index=0
+  %x = bf16[256,512]{1,0:T(8,128)(2,1)} get-tuple-element(%q), index=1
+  %w = bf16[512,2048]{1,0:T(8,128)(2,1)} get-tuple-element(%q), index=2
+  %multiply_add_fusion.2 = bf16[256,2048]{1,0:T(8,128)(2,1)S(1)} fusion(%x, %w), kind=kOutput, calls=%fused_computation.1
+  ROOT %t = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)}, bf16[512,2048]{1,0:T(8,128)(2,1)}) tuple(%i, %x, %w)
+}
+
+ENTRY %main.4 (a: bf16[256,512], b: bf16[512,2048], l: bf16[4,8,256,128,1], r: bf16[4,8,48,128,64]) -> bf16[4,8,256,48,64] {
+  %a = bf16[256,512]{1,0:T(8,128)(2,1)} parameter(0)
+  %b = bf16[512,2048]{1,0:T(8,128)(2,1)} parameter(1)
+  %l = bf16[4,8,256,128,1]{3,2,4,1,0:T(8,128)(2,1)} parameter(2)
+  %r = bf16[4,8,48,128,64]{4,3,2,1,0:T(8,128)(2,1)} parameter(3)
+  %z = s32[]{:T(128)} constant(0)
+  %t0 = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)}, bf16[512,2048]{1,0:T(8,128)(2,1)}) tuple(%z, %a, %b)
+  %w.5 = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)}, bf16[512,2048]{1,0:T(8,128)(2,1)}) while(%t0), condition=%cond.2, body=%body.3, backend_config={"known_trip_count":{"n":"4"}}
+  ROOT %conv.6 = bf16[4,8,256,48,64]{2,4,3,1,0:T(8,128)(2,1)} convolution(%l, %r), window={size=4x8x48 stride=3x7x1 pad=0_0x0_0x47_47 lhs_dilate=4x8x1 rhs_reversal=0x0x1}, dim_labels=01bf2_012io->01b2f
+}
+"""
+
+
+def test_tpu_layouts_never_reach_opcode_or_dims():
+    mod = parse_hlo(TPU_SAMPLE)
+    assert mod.entry == "main.4"
+    body = mod.computations["body.3"]
+    assert [i.opcode for i in body.instructions] == [
+        "parameter", "get-tuple-element", "get-tuple-element",
+        "get-tuple-element", "fusion", "tuple"]
+    assert body.find("multiply_add_fusion.2").shapes == [
+        Shape("bf16", (256, 2048))]
+    assert [s.dims for s in body.find("q").shapes] == [
+        (), (256, 512), (512, 2048)]
+    assert parse_shape_str("bf16[512,4096]{1,0:T(8,128)(2,1)S(1)}") == [
+        Shape("bf16", (512, 4096))]
+
+
+def test_tpu_convolution_flops():
+    mod = parse_hlo(TPU_SAMPLE)
+    entry = mod.entry_computation
+    # batched matmul: one tap per window dim meets an input element, so
+    # the contraction is the kernel's 128 input features
+    assert conv_flops(mod, entry.find("conv.6"), "main.4") == \
+        2.0 * (4 * 8 * 256 * 48 * 64) * 128
+    total = sum(instruction_flops(mod, ins, c) * m
+                for ins, m, c in walk_instructions(mod))
+    assert total == 4 * 2 * 256 * 512 * 2048 + 2.0 * (4 * 8 * 256 * 48 * 64) * 128
+
+
+def test_known_trip_count_wins_over_condition_constant():
+    mod = parse_hlo(TPU_SAMPLE)
+    w = mod.entry_computation.find("w.5")
+    assert while_trip_count(mod, "cond.2") == 7
+    assert loop_trip_count(mod, w) == 4

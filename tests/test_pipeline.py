@@ -1,9 +1,4 @@
 """GPipe pipeline over a stage axis: forward equivalence + trainability."""
-import pytest
-
-pytestmark = pytest.mark.skip(
-    reason="pre-existing at seed: parallel/pipeline.py's shard_map+ppermute "
-           "stage loop fails on jax 0.4.37 — see ROADMAP 'jax 0.4.37 compat'")
 
 
 def test_pipeline_matches_sequential(subproc):
