@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import ParamSpec, rms_norm, rms_norm_specs, rope
+from repro.obs import scopes
 
 NEG_INF = -2.0e38
 
@@ -140,7 +141,13 @@ def flash_attention_ref(q, k, v, *, scale, causal=True, window=0,
                     msk &= (pq_i[:, None] - pk_j[None, :]) < window
                 msk &= pk_j[None, :] < Sk                        # kv padding
                 s = jnp.where(msk[None, None, None], s, NEG_INF)
-                m_new = jnp.maximum(m, s.max(axis=-1))
+                # the running max only keeps exp() in range; the output
+                # does not depend on it, so no gradient goes through it.
+                # (Through the max's own gradient, which divides by the
+                # count of scores equal to the max, dq and dk of this scan
+                # came out all NaN on a TPU v5e at granite-3-8b's widths.)
+                m_new = jax.lax.stop_gradient(
+                    jnp.maximum(m, s.max(axis=-1)))
                 alpha = jnp.exp(m - m_new)
                 pr = jnp.exp(s - m_new[..., None])
                 l_new = l * alpha + pr.sum(axis=-1)
@@ -224,27 +231,30 @@ def attention_apply(p, x, cfg, ctx, kind, memory=None, positions=None):
     causal = kind in ("global", "local")
     window = cfg.local_window if kind == "local" else 0
 
-    if impl in ("pallas", "interpret"):
-        from repro.kernels import ops as kops
-        o = kops.flash_attention(qg, k, v, causal=causal, window=window,
-                                 scale=scale, interpret=(impl == "interpret"))
-    elif impl == "naive" or not causal:
-        # cross / encoder attention: full (no mask or memory-length mask)
-        Sk = k.shape[1]
-        if causal:
-            msk = jnp.tril(jnp.ones((S, Sk), bool))[None, None, None]
+    with jax.named_scope(scopes.SEQ_MIX):
+        if impl in ("pallas", "interpret"):
+            from repro.kernels import ops as kops
+            o = kops.flash_attention(qg, k, v, causal=causal, window=window,
+                                     scale=scale,
+                                     interpret=(impl == "interpret"))
+        elif impl == "naive" or not causal:
+            # cross / encoder attention: full (no mask or memory-length mask)
+            Sk = k.shape[1]
+            if causal:
+                msk = jnp.tril(jnp.ones((S, Sk), bool))[None, None, None]
+            else:
+                msk = jnp.ones((1, 1, 1, 1, 1), bool)
+            if impl == "naive" and causal and window:
+                qp = jnp.arange(S)[:, None]
+                kp = jnp.arange(Sk)[None, :]
+                msk = ((kp <= qp) & (qp - kp < window))[None, None, None]
+            o = naive_attention(qg, k, v, msk, scale)
+        elif kind == "local":
+            o = local_block_attention(qg, k, v, scale=scale, window=window)
         else:
-            msk = jnp.ones((1, 1, 1, 1, 1), bool)
-        if impl == "naive" and causal and window:
-            qp = jnp.arange(S)[:, None]
-            kp = jnp.arange(Sk)[None, :]
-            msk = ((kp <= qp) & (qp - kp < window))[None, None, None]
-        o = naive_attention(qg, k, v, msk, scale)
-    elif kind == "local":
-        o = local_block_attention(qg, k, v, scale=scale, window=window)
-    else:
-        o = flash_attention_ref(qg, k, v, scale=scale, causal=True,
-                                q_block=ctx.q_block, kv_block=ctx.kv_block)
+            o = flash_attention_ref(qg, k, v, scale=scale, causal=True,
+                                    q_block=ctx.q_block,
+                                    kv_block=ctx.kv_block)
     o = _ungroup(o)
     o = ctx.shard(o, "batch", None, "heads", None)
     return _out_proj(p, o, ctx, gated=(kind == "cross")), (k, v)

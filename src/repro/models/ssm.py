@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import ParamSpec
+from repro.obs import scopes
 
 
 def ssd_specs(cfg):
@@ -119,14 +120,15 @@ def ssd_block_apply(p, x, cfg, ctx, collect_cache=False):
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
     xh = xs.reshape(B_, S_, nh, hp).astype(jnp.float32)
-    if ctx.attn_impl in ("pallas", "interpret"):
-        from repro.kernels import ops as kops
-        y, S_final = kops.ssd(xh, dt, A, Bs.astype(jnp.float32),
-                              Cs.astype(jnp.float32), chunk=cfg.ssm_chunk,
-                              interpret=(ctx.attn_impl == "interpret"))
-    else:
-        y, S_final = ssd_chunked(xh, dt, A, Bs.astype(jnp.float32),
-                                 Cs.astype(jnp.float32), cfg.ssm_chunk)
+    with jax.named_scope(scopes.SEQ_MIX):
+        if ctx.attn_impl in ("pallas", "interpret"):
+            from repro.kernels import ops as kops
+            y, S_final = kops.ssd(xh, dt, A, Bs.astype(jnp.float32),
+                                  Cs.astype(jnp.float32), chunk=cfg.ssm_chunk,
+                                  interpret=(ctx.attn_impl == "interpret"))
+        else:
+            y, S_final = ssd_chunked(xh, dt, A, Bs.astype(jnp.float32),
+                                     Cs.astype(jnp.float32), cfg.ssm_chunk)
     cache = None
     if collect_cache:
         cw = cfg.conv_width

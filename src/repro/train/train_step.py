@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ParallelConfig
 from repro.models.model import Ctx, Model
+from repro.obs import scopes
 from repro.parallel.collectives import make_compressed_value_and_grad
 from repro.parallel.mesh import POD_AXIS, DATA_AXIS
 from repro.parallel.sharding import make_shard_fn
@@ -93,8 +94,9 @@ def make_train_step(model: Model, opt_cfg: OptConfig,
                 loss_fn, has_aux=True)(params, batch)
             new_err = state.err
 
-        new_params, new_opt, opt_metrics = adamw_update(
-            opt_cfg, params, grads, state.opt)
+        with jax.named_scope(scopes.OPTIMIZER):
+            new_params, new_opt, opt_metrics = adamw_update(
+                opt_cfg, params, grads, state.opt)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss

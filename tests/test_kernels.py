@@ -107,6 +107,37 @@ def test_ssd_chunked_model_path_matches_oracle():
     np.testing.assert_allclose(np.asarray(sf), np.asarray(sfr), atol=2e-3)
 
 
+@pytest.mark.parametrize("window", [0, 24])
+def test_flash_ref_gradient_matches_oracle(window):
+    """The chunked flash takes no gradient through its running max (the
+    output does not depend on it); its gradient is still the oracle's."""
+    from repro.models.attention import flash_attention_ref
+    rng = np.random.RandomState(7)
+    B, S, KV, G, hd = 1, 64, 2, 2, 8
+    q = jnp.asarray(rng.randn(B, S, KV, G, hd), jnp.float32)
+    k = jnp.asarray(rng.randn(B, S, KV, hd), jnp.float32)
+    v = jnp.asarray(rng.randn(B, S, KV, hd), jnp.float32)
+    do = jnp.asarray(rng.randn(B, S, KV, G, hd), jnp.float32)
+
+    def flash(q, k, v):
+        return jnp.vdot(do, flash_attention_ref(
+            q, k, v, scale=0.3, causal=True, window=window, q_block=16,
+            kv_block=32))
+
+    def oracle(q, k, v):
+        qf = jnp.moveaxis(q, 1, 3).reshape(B * KV * G, S, hd)
+        kf = jnp.moveaxis(k, 1, 2).reshape(B * KV, S, hd)
+        vf = jnp.moveaxis(v, 1, 2).reshape(B * KV, S, hd)
+        o = ref.flash_attention_oracle(qf, kf, vf, scale=0.3, causal=True,
+                                       window=window)
+        return jnp.vdot(do, jnp.moveaxis(o.reshape(B, KV, G, S, hd), 3, 1))
+
+    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+
+
 def test_flash_ref_matches_oracle_property():
     """Property-style sweep of the jnp chunked-flash used in the XLA path."""
     from repro.models.attention import flash_attention_ref

@@ -14,19 +14,23 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.capture import summarize_module
 from repro.core.convert import hlo_to_chakra
-from repro.core.hlo_parse import parse_hlo
+from repro.core.hlo_parse import parse_hlo, walk_instructions
 from repro.kernels import ops
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -106,3 +110,85 @@ def test_tpu_hlo_capture(name, one_chip):
     assert len(graph) > 0
     assert sum(n.attrs.get("flops", 0.0) for n in graph.nodes) == \
         pytest.approx(summary["parsed_flops"])
+
+
+# -- the train step's named scopes, as the benchmark's phase split reads them
+
+# what the profiler's trace does not show as an operation of its own: no
+# work (parameters, constants, tuples, their elements, bitcasts) or a
+# container of the operations in its body (loops, conditionals, calls)
+NOT_SHOWN = {"parameter", "constant", "tuple", "get-tuple-element",
+             "bitcast", "while", "conditional", "call"}
+
+
+def _step_hlo(cfg, devices, mesh_shape, kind, attn_impl, batch, seq):
+    """The program's step of ``kind`` ("train" | "prefill"), built as
+    ``repro.launch.train`` builds it (full remat, FSDP x TP rules),
+    compiled for ``devices``."""
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.launch.specs import input_specs, step_fn_for
+    from repro.parallel.mesh import make_mesh
+    mesh = make_mesh(mesh_shape, ("data", "model"), devices=devices)
+    shape = ShapeConfig("scopes", kind, seq, batch)
+    par = ParallelConfig(remat="full", attn_impl=attn_impl, fsdp=True,
+                         model_axis="tp", seq_shard=True)
+    args, shardings, model, par, donate = input_specs(cfg, shape, mesh, par)
+    step = step_fn_for(model, shape, par, mesh)
+    with jax.set_mesh(mesh):
+        return jax.jit(step, in_shardings=shardings,
+                       donate_argnums=donate).lower(*args).compile().as_text()
+
+
+def _shown_phases(text):
+    """[(instruction, its phase, whether it is under seq_mix)] for every
+    instruction the trace would show as an operation."""
+    from bench import phases
+    names = phases.op_names(text)
+    return [(ins, phases.phase(names.get(ins.name, "")),
+             phases.SEQ_MIX in phases.scopes_of(names.get(ins.name, "")))
+            for ins, _, _ in walk_instructions(parse_hlo(text))
+            if ins.opcode not in NOT_SHOWN]
+
+
+def _check_phases(shown):
+    unphased = [(i.name, i.opcode) for i, p, _ in shown if p == "other"]
+    assert not unphased, unphased[:20]
+    seq_mix = {p for _, p, seq in shown if seq}
+    assert seq_mix >= {"forward", "backward", "remat"}, seq_mix
+
+
+def _mamba():
+    """mamba2-780m's widths at depth 2."""
+    from repro.configs.registry import get_config
+    return get_config("mamba2-780m").replace(num_layers=2, sb_repeat=2)
+
+
+def test_mamba_step_scopes_cover_every_op(topo):
+    """Every operation of the train step has a phase, and the SSD core
+    (seq_mix) runs in the forward, the recompute and the backward."""
+    shown = _shown_phases(_step_hlo(_mamba(), topo.devices[:1], (1, 1),
+                                    "train", "xla", batch=1, seq=512))
+    _check_phases(shown)
+
+
+def test_pallas_ssd_kernel_is_under_seq_mix(topo):
+    """The Pallas SSD kernel takes the place of the seq_mix scope's body.
+    It has no VJP, so the step that carries it is the forward (prefill)."""
+    shown = _shown_phases(_step_hlo(_mamba(), topo.devices[:1], (1, 1),
+                                    "prefill", "pallas", batch=1, seq=512))
+    kernels = [(i.name, seq) for i, _, seq in shown
+               if 'custom_call_target="tpu_custom_call"' in i.raw]
+    assert kernels and all(seq for _, seq in kernels), kernels
+
+
+def test_granite_2x2_step_scopes_cover_every_op(topo):
+    """The dense GQA smoke model on a 2x2 FSDP x TP mesh: the collectives
+    the partitioner adds carry the phase they serve, like every other
+    operation."""
+    from repro.configs.registry import get_config
+    cfg = get_config("granite-3-8b", smoke=True)
+    shown = _shown_phases(_step_hlo(cfg, topo.devices, (2, 2), "train",
+                                    "xla", batch=4, seq=256))
+    _check_phases(shown)
+    colls = {i.collective_kind for i, _, _ in shown if i.is_collective}
+    assert {"all-gather", "all-reduce"} <= colls, colls
