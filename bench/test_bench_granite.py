@@ -1,8 +1,9 @@
 """The dense GQA configuration (granite-3-8b-l10) at smoke widths on the
 CPU: its FLOPs by hand, its parameter count against the program's tree,
 and whole runs of the harness against its plain reference, sound and with
-the timed path broken. No cell runs it yet: on a v5e the program's first
-gradient at its widths is not finite (PERF.md, Open questions 3)."""
+the timed path broken; and the tool that reads the numbers a cell's limits
+are set from (``bench/readings.py``). At full widths it is the cell
+``granite-3-8b-l10.train-b4s4096``."""
 from __future__ import annotations
 
 import copy
@@ -14,9 +15,9 @@ import jax
 import numpy as np
 import pytest
 
-from bench import compare, program, reference_step, reflib, registry, run
+from bench import (compare, program, readings, reference_step, reflib,
+                   registry, run)
 from bench.conftest import LIMITS, TRAFFIC, cpu_check, write
-from bench.readings import half_batch
 
 NAME = "granite-3-8b-l10"
 SEED = 3_000_000_019
@@ -117,7 +118,7 @@ def test_sound_run_is_correct(granite_root):
 
 
 def test_half_batch_is_not_correct(granite_root):
-    out = run.run(args(), check=cpu_check, alter=half_batch)
+    out = run.run(args(), check=cpu_check, alter=readings.half_batch)
     assert not out["correct"], out["compared"]
 
 
@@ -141,3 +142,28 @@ def test_float8_control_is_not_correct(granite_root):
     assert compare.verdict(prog_nums, limits)[0], prog_nums
     assert not compare.verdict(ctrl_nums, limits)[0], ctrl_nums
     assert ctrl_nums["grad_cos"] > 10 * prog_nums["grad_cos"]
+
+
+def test_readings_of_the_tiny_cell(granite_root, monkeypatch, capsys):
+    """The calibration tool: a line for each seed, control and fault, a
+    summary whose sound ``grad_cos`` lies under its faults', and every
+    sound reading within the cell's limits."""
+    monkeypatch.setattr(run, "check_device", cpu_check)
+    monkeypatch.setattr(run, "enable_cache", lambda: None)
+    seeds = [SEED, 17_179_869_209]
+    assert readings.main([
+        "--workload", "tiny-granite.t",
+        "--seeds", ",".join(map(str, seeds)),
+        "--control-seeds", str(seeds[0]),
+        "--fault-seeds", str(seeds[1])]) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    rows = [x for x in lines if "kind" in x]
+    assert sorted((r["kind"], r["seed"]) for r in rows) == sorted(
+        [("program", s) for s in seeds]
+        + [("control", seeds[0]), ("half_batch", seeds[1])])
+    summary = lines[-1]["summary"]
+    assert summary["grad_cos"]["lower"] < summary["grad_cos"]["upper"]
+    limits = registry.limits("tiny-granite.t")
+    for r in rows:
+        if r["kind"] == "program":
+            assert compare.verdict(r, limits)[0], r

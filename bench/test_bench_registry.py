@@ -20,7 +20,11 @@ def test_benchmark_file_names_files_that_exist():
     for w in bench["workloads"]:
         conf = registry.config(w["config"], bench)
         assert registry.traffic(w["traffic"])["kind"] == "train"
-        assert set(registry.limits(w["name"])) == {
+        # a cell compares the numbers with an upper reading; grad_cos
+        # (float8 arithmetic) and update_gap (a state left unchanged) are
+        # the only ones that see those faults
+        assert {"grad_cos", "update_gap"} <= set(
+            registry.limits(w["name"])) <= {
             "loss_gap", "grad_gap", "grad_cos", "update_gap"}
         registry.reference(conf["reference"])
         registry.flops(conf["flops"])
