@@ -140,12 +140,12 @@ def _step_hlo(cfg, devices, mesh_shape, kind, attn_impl, batch, seq):
 
 
 def _shown_phases(text):
-    """[(instruction, its phase, whether it is under seq_mix)] for every
+    """[(instruction, its phase, the scopes of its op name)] for every
     instruction the trace would show as an operation."""
     from bench import phases
     names = phases.op_names(text)
     return [(ins, phases.phase(names.get(ins.name, "")),
-             phases.SEQ_MIX in phases.scopes_of(names.get(ins.name, "")))
+             phases.scopes_of(names.get(ins.name, "")))
             for ins, _, _ in walk_instructions(parse_hlo(text))
             if ins.opcode not in NOT_SHOWN]
 
@@ -153,7 +153,7 @@ def _shown_phases(text):
 def _check_phases(shown):
     unphased = [(i.name, i.opcode) for i, p, _ in shown if p == "other"]
     assert not unphased, unphased[:20]
-    seq_mix = {p for _, p, seq in shown if seq}
+    seq_mix = {p for _, p, scopes in shown if "seq_mix" in scopes}
     assert seq_mix >= {"forward", "backward", "remat"}, seq_mix
 
 
@@ -176,7 +176,7 @@ def test_pallas_ssd_kernel_is_under_seq_mix(topo):
     It has no VJP, so the step that carries it is the forward (prefill)."""
     shown = _shown_phases(_step_hlo(_mamba(), topo.devices[:1], (1, 1),
                                     "prefill", "pallas", batch=1, seq=512))
-    kernels = [(i.name, seq) for i, _, seq in shown
+    kernels = [(i.name, "seq_mix" in scopes) for i, _, scopes in shown
                if 'custom_call_target="tpu_custom_call"' in i.raw]
     assert kernels and all(seq for _, seq in kernels), kernels
 
@@ -192,3 +192,7 @@ def test_granite_2x2_step_scopes_cover_every_op(topo):
     _check_phases(shown)
     colls = {i.collective_kind for i, _, _ in shown if i.is_collective}
     assert {"all-gather", "all-reduce"} <= colls, colls
+    # the flash core's own backward opens its block scope, as the forward's
+    # body does, so Flint's fused-kernel accounting sees both alike
+    vmem = {p for _, p, scopes in shown if "flash_vmem" in scopes}
+    assert "backward" in vmem, vmem
