@@ -26,6 +26,7 @@ import json
 import os
 import sys
 import time
+from types import SimpleNamespace
 
 sys.path[:0] = [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                 os.path.join(os.path.dirname(os.path.dirname(
@@ -56,8 +57,8 @@ def report(tr, hlo_text, steps):
     """Log the ``[scopes]`` line and the longest operations of each phase;
     returns (split, the split's seconds from inherited op names, the top
     operations, the number of instructions named)."""
-    names = phases.op_names(hlo_text)
-    split = phases.phase_seconds(tr, names, steps)
+    ctx = SimpleNamespace(trace=tr, hlo=hlo_text, traced_steps=steps)
+    split, names = phases.traced_split(ctx), ctx.op_names  # the readers' split
     borrowed = phases.phase_seconds(
         tr, phases.inherited(names, phases.own_op_names(hlo_text)), steps)
     summed = sum(e.end - e.start for evs in tr.ops.values()

@@ -145,3 +145,24 @@ def phase_seconds(tr: devtrace.Trace, names: Dict[str, str],
         for k in keys:
             total[k] += devtrace.length(devtrace.union(by[k]))
     return {k: v / len(tr.ops) / steps * 1e-9 for k, v in total.items()}
+
+
+def traced_split(ctx) -> Dict[str, float]:
+    """``phase_seconds`` of a traced run's context (``trace``, ``hlo``,
+    ``traced_steps``): the HLO parsed and the split made once, and both kept
+    on ``ctx`` (``op_names``, ``phase_s``) for every reader of the run."""
+    if getattr(ctx, "phase_s", None) is None:
+        ctx.op_names = op_names(ctx.hlo)
+        ctx.phase_s = phase_seconds(ctx.trace, ctx.op_names, ctx.traced_steps)
+    return ctx.phase_s
+
+
+def reader(key: str):
+    """A per-layer metric's ``read(ctx)``: the device seconds a step of
+    ``key`` (a phase, ``other`` or ``seq_mix``) by ``traced_split``; nothing
+    when the run was not traced or no operation falls in ``key``."""
+    def read(ctx):
+        if ctx.trace is None or getattr(ctx, "hlo", None) is None:
+            return None
+        return traced_split(ctx)[key] or None
+    return read

@@ -9,9 +9,10 @@ from the persistent cache in ``.jax_cache/``), and runs the first three
 steps through the window's own call, reading what the comparison needs.
 The window then runs whole steps until ``--seconds`` have passed. With
 ``--trace 1`` a few more steps run under the profiler and the per-layer
-metrics come from that trace. Last, the program's state is freed and the
-plain reference follows the same three steps; ``correct`` says whether the
-program stayed within the limits of ``limits/<cell>.json``.
+metrics come from that trace, the op names of the executable that ran and
+the last traced step's scalar outputs. Last, the program's state is freed
+and the plain reference follows the same three steps; ``correct`` says
+whether the program stayed within the limits of ``limits/<cell>.json``.
 
 The last line of stdout is one JSON object. A run that finds no TPU, a
 device kind missing from ``peaks.json``, or fewer chips than the cell
@@ -175,7 +176,9 @@ def traced(ns, steps: int):
     """``steps`` more steps under the profiler, one in flight as in the
     window, each host phase in a ``bench.*`` span.  The ``bench.window``
     span runs from the first dispatch to the end of the last step, so it
-    holds exactly ``steps`` steps.  Returns the reduced trace."""
+    holds exactly ``steps`` steps.  Returns the reduced trace; the last
+    step's scalar outputs are fetched to the host after the window has
+    ended, into ``ns.step_out``."""
     import jax
     from bench import devtrace
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
@@ -204,6 +207,8 @@ def traced(ns, steps: int):
     finally:
         jax.profiler.stop_trace()
     ns.state, ns.next_batch = state, i
+    ns.step_out = {k: float(v) for k, v in jax.device_get(m).items()
+                   if v.ndim == 0}
     tr = devtrace.load(devtrace.find_xplane(TRACE_DIR))
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     return tr
@@ -268,9 +273,11 @@ def run(args, check=check_device, alter=None) -> dict:
         f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     failed = sum(1 for x in losses if not math.isfinite(x))
 
-    trace = None
+    trace = hlo = None
     if args.trace:
         trace = traced(ns, TRACED_STEPS)
+        # the executable that ran: its op names are the trace's instructions'
+        hlo = ns.step.as_text()
     peak_bytes = memory_peak(devices)
     log(f"[memory] peak_bytes_in_use {peak_bytes} B on the fullest chip")
 
@@ -294,14 +301,19 @@ def run(args, check=check_device, alter=None) -> dict:
     if args.trace:
         from bench import devtrace
         ctx = SimpleNamespace(
-            trace=trace, traced_steps=TRACED_STEPS, n_chips=prog.n_chips,
+            trace=trace, hlo=hlo, step_out=ns.step_out,
+            traced_steps=TRACED_STEPS, n_chips=prog.n_chips,
             peak=peak, model_flops_per_step=registry.flops(
                 conf["flops"]).model_flops_per_step(conf, traffic))
         metrics = {}
+        t = time.perf_counter()
         for m in registry.cell_metrics(args.workload, bench, "per_layer"):
             v = registry.metric_reader(m["name"]).read(ctx)
             if v is not None:
                 metrics[m["name"]] = (v, m["unit"])
+        log(f"[per_layer] {len(metrics)} metrics read in "
+            f"{time.perf_counter() - t:.2f}s from {len(hlo)} characters of "
+            f"HLO")
         device = dict(device, busy_s=devtrace.busy_s(trace),
                       window_s=devtrace.window_s(trace))
     else:

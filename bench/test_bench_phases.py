@@ -201,3 +201,30 @@ def test_recorded_report(capsys):
     lines = [s for s in capsys.readouterr().out.splitlines()
              if s.startswith("[scopes] ")]
     assert len(lines) == 1 and "inherited op name: forward" in lines[0]
+
+
+READERS = {"fwd_device_s": "forward", "bwd_device_s": "backward",
+           "remat_device_s": "remat", "optimizer_device_s": "optimizer",
+           "seq_mix_device_s": "seq_mix"}
+
+
+def test_readers_on_the_recorded_step(capsys):
+    """The per-phase metrics of a traced run, read from its trace and the
+    HLO text of the executable that ran, are the seconds ``phase_split``
+    prints for that trace; with ``other`` they sum to the busy time."""
+    from types import SimpleNamespace
+
+    from bench import phase_split, registry
+    tr, _ = recorded()
+    with open(os.path.join(HERE, "testdata", "scoped_step.hlo.txt")) as f:
+        text = f.read()
+    ctx = SimpleNamespace(trace=tr, hlo=text, traced_steps=2)
+    got = {n: registry.metric_reader(n).read(ctx) for n in READERS}
+    split = phase_split.report(tr, text, 2)[0]
+    assert got == {n: split[k] for n, k in READERS.items()}
+    total = sum(got[n] for n in READERS if n != "seq_mix_device_s")
+    assert total + split["other"] == pytest.approx(devtrace.busy_s(tr) / 2)
+    # without the executable's text (an untraced run) there is nothing
+    # to name the operations by
+    ctx.hlo = None
+    assert all(registry.metric_reader(n).read(ctx) is None for n in READERS)

@@ -70,6 +70,57 @@ def test_new_files_are_found_by_name(tiny_root):
     assert registry.metric_reader("steps_traced").read(Ctx) == 3.0
 
 
+def test_a_new_expert_leaf_is_drawn_by_its_names():
+    """A leaf added under a ``moe`` node, (layers, experts, in, out) beside
+    the router, is a routed expert by its names and shape: drawn at fan-in
+    ``in`` with no edit to ``weights.py``."""
+    import math
+
+    import jax
+    import numpy as np
+    sds = jax.ShapeDtypeStruct
+    tree = {"blocks": {"sb": {"slot0": {"moe": {
+        "router": sds((2, 64, 8), np.float32),
+        "w_up": sds((2, 8, 64, 256), jax.numpy.bfloat16)}}}}}
+    x = np.asarray(weights.leaf(tree, weights.seed_words(7), 1), np.float32)
+    assert x.shape == (2, 8, 64, 256)
+    assert x.std() * math.sqrt(64) == pytest.approx(0.8796, rel=0.02)
+
+
+def test_a_reader_of_the_step_outputs_is_found_by_name(tiny_root, tmp_path,
+                                                       monkeypatch):
+    """A per-layer metric that reads the last traced step's outputs
+    (``ctx.step_out``) is one more file and entry; a whole traced run of
+    the tiny cell reports it. The CPU has no device plane to trace, so a
+    hand-made trace stands in for the profiler's."""
+    import math
+
+    from bench import devtrace, run
+    from bench.conftest import cpu_check
+    from bench.devtrace import Span
+    with open(os.path.join(registry.BENCH, "metrics", "step_loss.py"),
+              "w") as f:
+        f.write("def read(ctx):\n    return ctx.step_out['loss']\n")
+    path = os.path.join(registry.ROOT, "BENCHMARK.json")
+    bench = json.load(open(path))
+    bench["per_layer"].append({"name": "step_loss", "unit": "nats",
+                               "better": "lower", "source": "program_counter",
+                               "layer": "model step",
+                               "moves": "train_tokens_per_s"})
+    write(path, bench)
+    monkeypatch.setattr(run, "TRACE_DIR", str(tmp_path / "trace"))
+    monkeypatch.setattr(devtrace, "load", lambda _: devtrace.make(
+        {0: [Span("fusion.1", 0, 10)]}, [Span("bench.window", 0, 10)]))
+    monkeypatch.setattr(run, "flint_prediction", lambda *a: None)
+    out = run.run(run.parse_args(["--workload", "tiny-mamba2.t", "--seed",
+                                  "3000000019", "--seconds", "0.2",
+                                  "--trace", "1"]), check=cpu_check)
+    assert out["correct"], out["compared"]
+    loss = out["metrics"]["step_loss"]
+    assert loss["unit"] == "nats"
+    assert math.isfinite(loss["value"]) and 0 < loss["value"] < 10
+
+
 @pytest.mark.parametrize("bad", ["../peaks", "a/b", "", "x" * 65])
 def test_names_that_could_leave_the_directory_are_refused(bad):
     with pytest.raises(registry.UnknownName):

@@ -116,8 +116,11 @@ def test_a_host_stall_moves_idle_not_mfu():
     assert idle.read(ctx(long, 1, 1e3)) == pytest.approx(55.0)
 
 
-@pytest.mark.parametrize("name", ["train_mfu", "device_idle"])
+@pytest.mark.parametrize("name", [
+    "train_mfu", "device_idle", "fwd_device_s", "bwd_device_s",
+    "remat_device_s", "optimizer_device_s", "seq_mix_device_s"])
 def test_readers_without_a_trace_return_nothing(name):
-    untraced = SimpleNamespace(trace=None, traced_steps=3, n_chips=1,
-                               peak={}, model_flops_per_step=1e3)
+    untraced = SimpleNamespace(trace=None, hlo=None, step_out=None,
+                               traced_steps=3, n_chips=1, peak={},
+                               model_flops_per_step=1e3)
     assert registry.metric_reader(name).read(untraced) is None

@@ -59,6 +59,8 @@ def _draw(names, shape, key, siblings):
     if len(core) >= 2:
         if last == "table":                   # embedding, tied head
             fan_in = core[1]
+        elif _routed_expert(names, core):     # (E, fan-in, out)
+            fan_in = core[1]
         elif last == "wo" and len(core) == 3:  # attention out (heads, hd, d)
             fan_in = core[0] * core[1]
         else:
@@ -66,6 +68,13 @@ def _draw(names, shape, key, siblings):
         return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
                 / math.sqrt(fan_in))
     raise ValueError(f"no init rule for parameter {'/'.join(names)} {shape}")
+
+
+def _routed_expert(names, core) -> bool:
+    """A leaf under a ``moe`` node whose per-layer core is (experts, in, out):
+    each expert is a matrix of its own, with fan-in ``in``.  The experts
+    held may be fewer than the router's outputs (a chip's share of them)."""
+    return len(names) >= 2 and names[-2] == "moe" and len(core) == 3
 
 
 def _siblings(named, i):
